@@ -14,6 +14,10 @@ from repro.batching import CapsCalibrator, make_policy, root_batches
 from repro.configs.base import GNNConfig, TrainConfig
 from repro.core.reorder import prepare
 from repro.graphs import synthetic
+from repro.runtime import use_compile_cache
+
+# every benchmark script imports this module first
+use_compile_cache()
 
 POLICIES = {
     "RAND-ROOTS/p0.5": make_policy("rand"),

@@ -23,32 +23,30 @@ plus a `bit_identity` verdict: a 1-replica mesh losses-`==` the
 single-device trainer over the probe steps — the determinism headline
 of the sharded path, asserted by CI on every run.
 
-    PYTHONPATH=src python benchmarks/dist_bench.py [--smoke]
+The platform comes from the environment, never from this script. The
+CPU configuration forces four host devices:
+
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+        PYTHONPATH=.:src python -m benchmarks.dist_bench [--smoke]
 
 CPU-simulated mesh numbers are layout/contract validation, not kernel
 perf (see the `_meta` note in the artifact).
 """
 from __future__ import annotations
 
+import argparse
+import json
 import os
+import time
 
-# the forced multi-device CPU topology must exist BEFORE jax initializes
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import jax
 
-import argparse          # noqa: E402
-import json              # noqa: E402
-import time              # noqa: E402
-
-import jax               # noqa: E402
-import numpy as np       # noqa: E402
-
-from benchmarks.common import _REPO_ROOT, dataset, write_bench_json  # noqa: E402
-from repro.configs.base import GNNConfig, TrainConfig                # noqa: E402
-from repro.dist import gnn as dist_gnn                               # noqa: E402
-from repro.obs import report as obs_report                           # noqa: E402
-from repro.obs import trace as obs_trace                             # noqa: E402
-from repro.train.gnn_loop import GNNTrainer                          # noqa: E402
+from benchmarks.common import _REPO_ROOT, dataset, write_bench_json
+from repro.configs.base import GNNConfig, TrainConfig
+from repro.dist import gnn as dist_gnn
+from repro.obs import report as obs_report
+from repro.obs import trace as obs_trace
+from repro.train.gnn_loop import GNNTrainer
 
 BENCH_DIST_JSON = os.path.join(_REPO_ROOT, "BENCH_dist.json")
 
@@ -128,8 +126,9 @@ def main():
     args = ap.parse_args()
     rep = run(smoke=args.smoke)
     assert rep["n_replicas"] == 4, (
-        "dist bench expects the forced 4-device CPU mesh; got "
-        f"{rep['n_replicas']} (is XLA_FLAGS overridden?)")
+        "dist bench expects a 4-device mesh; got "
+        f"{rep['n_replicas']} (on CPU, set XLA_FLAGS="
+        "--xla_force_host_platform_device_count=4)")
     write_bench_json({"dist/gnn": rep}, path=BENCH_DIST_JSON)
     print(json.dumps(rep, indent=1, sort_keys=True))
 

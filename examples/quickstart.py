@@ -11,10 +11,12 @@ from repro.batching import available_policies, make_policy
 from repro.configs.base import GNNConfig, TrainConfig
 from repro.core.reorder import prepare
 from repro.graphs import synthetic
+from repro.runtime import use_compile_cache
 from repro.train.gnn_loop import train_once
 
 
 def main():
+    use_compile_cache()
     print("== generating community-structured graph (tiny SBM) ==")
     g = prepare(synthetic.load("tiny"), oracle=False)   # runs Louvain
     print(f"graph: {g.num_nodes} nodes, {g.num_edges} edges, "

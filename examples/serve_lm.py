@@ -12,10 +12,12 @@ import jax.numpy as jnp
 from repro.configs.registry import LM_ARCHS, get_config
 from repro.launch.specs import materialize, prefill_batch_specs
 from repro.models.lm import transformer
+from repro.runtime import use_compile_cache
 from repro.train.train_step import make_decode_step, make_prefill_step
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b", choices=list(LM_ARCHS))
     ap.add_argument("--batch", type=int, default=4)

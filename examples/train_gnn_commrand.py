@@ -16,10 +16,12 @@ from repro.batching import CapsCalibrator, make_policy
 from repro.configs.base import GNNConfig, TrainConfig
 from repro.core.reorder import prepare
 from repro.graphs import synthetic
+from repro.runtime import use_compile_cache
 from repro.train.gnn_loop import GNNTrainer
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="reddit-like",
                     choices=sorted(synthetic.DATASETS))

@@ -8,10 +8,12 @@ import argparse
 from repro.configs.base import TrainConfig
 from repro.configs.registry import LM_ARCHS, get_config
 from repro.data.pipeline import BlockShuffler, LMStream, SyntheticTokens
+from repro.runtime import use_compile_cache
 from repro.train.lm_loop import LMTrainer
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b", choices=list(LM_ARCHS))
     ap.add_argument("--steps", type=int, default=200)

@@ -27,14 +27,6 @@ def owner_of(ids, n_per_shard):
     return ids // n_per_shard
 
 
-def _axis_size(axis: str) -> int:
-    # jax.lax.axis_size only exists on newer jax; psum(1) is the portable
-    # spelling (constant-folded, no collective is emitted)
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
-
-
 def halo_gather(feats_local, ids, *, n_per_shard: int, r_cap: int,
                 halo: int, axis: str = "shard"):
     """Inside shard_map. feats_local: (Ns, F); ids: (K,) global node ids
@@ -44,7 +36,7 @@ def halo_gather(feats_local, ids, *, n_per_shard: int, r_cap: int,
     calibration must pick (halo, r_cap) so this is negligible for the
     policy in use.
     """
-    D = _axis_size(axis)
+    D = lax.axis_size(axis)
     me = lax.axis_index(axis)
     K = ids.shape[0]
     F = feats_local.shape[1]
@@ -90,7 +82,7 @@ def global_gather(feats_local, ids, *, n_per_shard: int,
     Collective bytes ~ D * K * F — the structure-agnostic cost. Requests are
     served in `chunk`-sized waves to bound the (D, chunk, F) exchange
     buffer."""
-    D = _axis_size(axis)
+    D = lax.axis_size(axis)
     me = lax.axis_index(axis)
     n_total = n_per_shard * D
     K = ids.shape[0]

@@ -51,7 +51,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.batching.stream import BatchStream
 from repro.core import halo
 from repro.core import minibatch as mb
-from repro.dist.sharding import shard_map
 from repro.graphs.csr import Graph
 
 AXIS = "shard"
@@ -399,7 +398,8 @@ def make_sharded_steps(cfg, tcfg, mesh: Mesh, plan: ShardPlan,
     in_specs = (rep, rep, sh, feats_spec, rep, rep, rep, rep, rep, rep)
     out_specs = (rep, rep, rep, rep, rep, rep, rep,
                  {"loss": sh, "dropped": sh, "hits": sh, "misses": sh})
-    mapped = shard_map(per_replica, mesh, in_specs, out_specs)
+    mapped = jax.shard_map(per_replica, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     step = jax.jit(mapped, donate_argnums=(0, 1) if donate else ())
 
     def train_step(params, opt_state, batch, feats, degrees, lr, key,

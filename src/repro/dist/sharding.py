@@ -125,17 +125,6 @@ def to_shardings(spec_tree: Any, mesh: Mesh) -> Any:
                         is_leaf=lambda x: isinstance(x, P))
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs, check: bool = False):
-    """Version-portable shard_map: `jax.shard_map(check_vma=)` on new jax,
-    `jax.experimental.shard_map(check_rep=)` on 0.4.x."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check)
-
-
 # ---------------------------------------------------------------------------
 # parameter specs
 # ---------------------------------------------------------------------------

@@ -53,7 +53,8 @@ def _gather_agg_bwd(block_dst, interpret, res, g):
     x, idx, w = res
     dx = gather_agg_bwd_dx_pallas(idx, w, g, x.shape[0],
                                   interpret=interpret)
-    dw = gather_agg_bwd_dw_pallas(x, idx, g, interpret=interpret)
+    dw = gather_agg_bwd_dw_pallas(x, idx, g, block_dst=block_dst,
+                                  interpret=interpret)
     didx = np.zeros(idx.shape, jax.dtypes.float0)
     return dx.astype(x.dtype), didx, dw.astype(w.dtype)
 
@@ -61,7 +62,7 @@ def _gather_agg_bwd(block_dst, interpret, res, g):
 _gather_agg.defvjp(_gather_agg_fwd, _gather_agg_bwd)
 
 
-def gather_agg(x, idx, w, *, impl: str = "pallas", block_dst: int = 8):
+def gather_agg(x, idx, w, *, impl: str = "pallas", block_dst: int = 0):
     """Fused `out[i] = sum_j w[i,j] * x[idx[i,j]]`; differentiable in x, w.
 
     x: (n_src, F) float; idx: (n_dst, r) int (clipped to [0, n_src));

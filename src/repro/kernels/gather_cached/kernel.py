@@ -7,18 +7,11 @@ in the compact (C, F) cache array or in the global (N, F) feature matrix:
     out[k] = cache[pos[ids[k]]]   if pos[ids[k]] >= 0   (hit)
            = feats[ids[k]]        otherwise             (miss)
 
-Grid: one step per id, with ids PRE-PARTITIONED by hit flag outside the
-kernel (hits first — the same pre-sort trick `gather_agg`'s backward uses
-for consecutive accumulation). Both tables arrive through BlockSpec index
-maps driven by scalar-prefetched row arrays; the UNSELECTED table's row
-index is pinned to 0, and because the partition makes that pin contiguous
-(the whole miss tail pins the cache stream, the whole hit head pins the
-feats stream), the pipeline skips the re-fetch of an unchanged block — so
-HBM traffic is one row per id (+2 pinned rows), not two. That is the
-cache's bandwidth story: a hit never touches the global matrix.
-
-Output rows land at the ORIGINAL id positions via a scalar-prefetched
-inverse permutation; every output block is written exactly once.
+This is the tiled row gather of `repro.kernels.gather_agg` with fanout 1
+and two tables: each id is encoded as its global row (miss) or as
+-(cache row) - 1 (hit), and the kernel issues exactly one row DMA per id
+from the table the sign selects. That is the cache's bandwidth story: a hit
+never touches the global matrix. Output rows stay in id order.
 
 Backward needs no new kernel: d_cache/d_feats are masked scatter-adds of
 the cotangent rows, exactly `gather_agg_bwd_dx_pallas` with fanout 1 (see
@@ -26,42 +19,17 @@ the cotangent rows, exactly `gather_agg_bwd_dx_pallas` with fanout 1 (see
 """
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from repro.kernels.gather_agg.kernel import gather_tiles
 
 
-def _fwd_kernel(crow_ref, frow_ref, hit_ref, orow_ref, cache_ref, feats_ref,
-                o_ref):
-    del crow_ref, frow_ref, orow_ref    # consumed by the BlockSpec maps
-    e = pl.program_id(0)
-    o_ref[...] = jnp.where(hit_ref[e] > 0,
-                           cache_ref[...].astype(jnp.float32),
-                           feats_ref[...].astype(jnp.float32))
+def gather_cached_fwd_pallas(cache, feats, code, *, interpret: bool = False):
+    """cache: (C, F); feats: (N, F) of the same dtype; code: (M,) int32,
+    a global row v >= 0 (miss) or -(cache row) - 1 (hit). Returns (M, F)
+    float32."""
+    f = feats.shape[1]
 
+    def copy_rows(rows, _, o_ref):
+        o_ref[...] = rows[0][:, :f]
 
-def gather_cached_fwd_pallas(cache, feats, crow, frow, hit, orow, *,
-                             interpret: bool = False):
-    """cache: (C, F); feats: (N, F); crow/frow: (M,) int32 row to stream
-    from each table (0-pinned where the table is not selected); hit: (M,)
-    int32 selector; orow: (M,) int32 output row (the inverse of the
-    hit-partition permutation). Returns (M, F) float32. Callers partition
-    ids so `hit` is non-increasing (see module docstring)."""
-    M = crow.shape[0]
-    F = feats.shape[1]
-    return pl.pallas_call(
-        _fwd_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(M,),
-            in_specs=[
-                pl.BlockSpec((1, F), lambda e, cr, fr, h, orw: (cr[e], 0)),
-                pl.BlockSpec((1, F), lambda e, cr, fr, h, orw: (fr[e], 0)),
-            ],
-            out_specs=pl.BlockSpec((1, F),
-                                   lambda e, cr, fr, h, orw: (orw[e], 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((M, F), jnp.float32),
-        interpret=interpret,
-    )(crow, frow, hit, orow, cache, feats)
+    return gather_tiles((feats, cache), code.reshape(-1, 1), None,
+                        copy_rows, f, interpret=interpret)

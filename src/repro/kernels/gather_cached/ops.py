@@ -78,18 +78,9 @@ def cache_ref_updates(pos, ids, capacity: int):
 
 
 def _fwd_pallas(cache, feats, pos, ids, interpret):
-    N = feats.shape[0]
-    gid, sel, hit = _hit_mask(pos, ids, N)
-    # partition hits first: the unselected table's 0-pinned stream is then
-    # contiguous, so the pipeline never re-fetches it (see kernel.py)
-    order = jnp.argsort(jnp.where(hit, 0, 1)).astype(jnp.int32)
-    return gather_cached_fwd_pallas(
-        cache, feats,
-        crow=jnp.where(hit, sel, 0)[order].astype(jnp.int32),
-        frow=jnp.where(hit, 0, gid)[order].astype(jnp.int32),
-        hit=hit[order].astype(jnp.int32),
-        orow=order,
-        interpret=interpret)
+    gid, sel, hit = _hit_mask(pos, ids, feats.shape[0])
+    code = jnp.where(hit, -1 - sel, gid).astype(jnp.int32)
+    return gather_cached_fwd_pallas(cache, feats, code, interpret=interpret)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))

@@ -3,8 +3,8 @@
 `gather_cached_ref` is also the production `cache_impl="jnp"` path: XLA
 lowers the double gather + select well enough on CPU/GPU, but it always
 reads BOTH candidate rows (cache and global) per id — the Pallas kernel's
-hit-partitioned streaming is what makes a hit skip the global-matrix HBM
-read on TPU.
+one row DMA per id, from the selected table, is what makes a hit skip the
+global-matrix HBM read on TPU.
 """
 import jax.numpy as jnp
 

@@ -1,28 +1,35 @@
+"""LM sharding dry-run: lower and compile every (arch, shape) cell on the
+production mesh of 512 host devices, and record memory, cost and
+collective bytes.
+
+The platform comes from the environment, set before Python starts:
+
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=512 \
+        PYTHONPATH=src python -m repro.launch.dryrun --arch gemma3-1b
+
+`make_production_mesh` refuses to run on fewer devices.
+"""
+import argparse
+import json
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import (device count locks on
-# first init). Everything else follows.
+import re
+import time
+import traceback
 
-import argparse      # noqa: E402
-import json          # noqa: E402
-import re            # noqa: E402
-import time          # noqa: E402
-import traceback     # noqa: E402
+import numpy as np
 
-import numpy as np   # noqa: E402
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-import jax           # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
-
-from repro.configs.base import (SHAPES, TrainConfig, long_context_ok)  # noqa: E402
-from repro.configs.registry import LM_ARCHS, get_config  # noqa: E402
-from repro.dist import sharding as shd  # noqa: E402
-from repro.launch import specs as specs_mod  # noqa: E402
-from repro.launch.mesh import make_production_mesh  # noqa: E402
-from repro.models.lm import transformer  # noqa: E402
-from repro.optim import adamw  # noqa: E402
-from repro.train.train_step import (make_decode_step, make_prefill_step,  # noqa: E402
+from repro.configs.base import (SHAPES, TrainConfig, long_context_ok)
+from repro.configs.registry import LM_ARCHS, get_config
+from repro.dist import sharding as shd
+from repro.launch import specs as specs_mod
+from repro.launch.mesh import make_production_mesh
+from repro.models.lm import transformer
+from repro.optim import adamw
+from repro.train.train_step import (make_decode_step, make_prefill_step,
                                     make_train_step)
 
 ART_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
@@ -230,8 +237,6 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):       # jax 0.4.x returns [dict] per module
-        cost = cost[0] if cost else {}
     coll = collective_bytes(compiled.as_text())
     meta = {
         "arch": arch, "shape": shape_name,

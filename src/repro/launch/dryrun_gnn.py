@@ -1,23 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# Must precede any jax import (see dryrun.py).
-
-import argparse    # noqa: E402
-import json        # noqa: E402
-
-import numpy as np  # noqa: E402
-import jax          # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
-
-from repro.configs.base import GNNConfig, TrainConfig  # noqa: E402
-from repro.core import halo as halo_mod  # noqa: E402
-from repro.core.minibatch import Block, MiniBatch  # noqa: E402
-from repro.launch.dryrun import ART_DIR, collective_bytes  # noqa: E402
-from repro.models.gnn.models import apply_gnn, init_gnn  # noqa: E402
-from repro.optim import adamw  # noqa: E402
-from repro.train.losses import gnn_softmax_ce  # noqa: E402
-
 """Pod-scale GNN dry-run: the paper's pipeline at ogbn-papers100M scale.
 
 Topology stays on hosts (DGL-style CPU sampling; DESIGN.md §4) — the device
@@ -28,7 +8,29 @@ global fallback for RAND), then the SAGE tower + AdamW.
 
 Static caps per policy come from calibration on the papers-like synthetic
 graph (see EXPERIMENTS.md §Dry-run), scaled to papers100M's fanout tree.
+
+Needs 512 host devices, set in the environment before Python starts:
+
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=512 \
+        PYTHONPATH=src python -m repro.launch.dryrun_gnn
 """
+import argparse
+import json
+import os
+
+import numpy as np
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from repro.configs.base import GNNConfig, TrainConfig
+from repro.core import halo as halo_mod
+from repro.core.minibatch import Block, MiniBatch
+from repro.launch.dryrun import ART_DIR, collective_bytes
+from repro.models.gnn.models import apply_gnn, init_gnn
+from repro.optim import adamw
+from repro.train.losses import gnn_softmax_ce
+
 
 N_NODES = 111_059_956
 FEAT_DIM = 128
@@ -51,6 +53,9 @@ POLICY_CELLS = {
 
 def gnn_mesh(multi_pod: bool):
     devs = jax.devices()
+    if len(devs) < 512:
+        raise RuntimeError(f"the dry-run needs 512 devices, found "
+                           f"{len(devs)} (see the module docstring)")
     if multi_pod:
         return Mesh(np.asarray(devs[:512]).reshape(2, 256), ("pod", "shard"))
     return Mesh(np.asarray(devs[:256]).reshape(256,), ("shard",))
@@ -118,7 +123,8 @@ def lower_gnn_cell(policy_name: str, multi_pod: bool = False):
     in_specs_gather = (P("shard", None), P(batch_axes, None))
     out_specs_gather = (P(batch_axes, None, None), P(batch_axes))
 
-    @partial_shard_map(mesh, in_specs_gather, out_specs_gather)
+    @jax.shard_map(mesh=mesh, in_specs=in_specs_gather,
+                   out_specs=out_specs_gather, check_vma=False)
     def gather(feats_local, ids_b):
         x, dropped = halo_mod.gather_for_policy(
             feats_local, ids_b[0], n_per_shard=n_per_shard, r_cap=r_cap,
@@ -169,15 +175,6 @@ def lower_gnn_cell(policy_name: str, multi_pod: bool = False):
             caps[-1], FEAT_DIM, n_shard, r_cap, halo_w, mode),
     }
     return compiled, lowered, meta
-
-
-def partial_shard_map(mesh, in_specs, out_specs):
-    from repro.dist.sharding import shard_map
-
-    def deco(f):
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)
-    return deco
 
 
 def main():

@@ -1,9 +1,10 @@
 """Production mesh construction.
 
 `make_production_mesh` is a FUNCTION (not a module-level constant) so
-importing this module never touches jax device state. The dry-run launcher
-sets XLA_FLAGS=--xla_force_host_platform_device_count=512 before importing
-jax; smoke tests and benchmarks see the real single CPU device.
+importing this module never touches jax device state. The dry-run
+launchers need XLA_FLAGS=--xla_force_host_platform_device_count=512 in the
+environment before Python starts; smoke tests and benchmarks see the real
+devices.
 """
 from __future__ import annotations
 
@@ -22,7 +23,9 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     if len(devices) < need:
         raise RuntimeError(
             f"mesh {shape} needs {need} devices, found {len(devices)} — "
-            "run via launch/dryrun.py which forces 512 host devices")
+            "for a CPU dry run set JAX_PLATFORMS=cpu and "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=512 in the "
+            "environment before Python starts")
     return Mesh(np.asarray(devices[:need]).reshape(shape), axes)
 
 

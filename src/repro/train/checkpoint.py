@@ -27,6 +27,7 @@ import json
 import os
 import shutil
 import tempfile
+import tokenize
 import warnings
 import zlib
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -157,7 +158,10 @@ def _restore(ckpt_dir: str, step: int, like: Any, shardings: Any) -> tuple:
     for i, ref in enumerate(leaves):
         try:
             arr = np.load(os.path.join(path, f"leaf_{i}.npy"))
-        except (OSError, ValueError, EOFError) as e:
+        # a corrupt .npy header is parsed as a Python literal, so it can
+        # also raise SyntaxError or tokenize.TokenError
+        except (OSError, ValueError, EOFError, SyntaxError,
+                tokenize.TokenError) as e:
             raise CheckpointCorrupt(
                 f"{path}: leaf_{i}.npy unreadable: {e}") from e
         meta = refs[i]

@@ -415,8 +415,10 @@ class GNNTrainer:
     def warmup(self):
         """Trigger all jit compilations without disturbing training state
         (so per-epoch timings measure steady-state throughput)."""
-        saved = (jax.tree.map(lambda x: x, self.params),
-                 jax.tree.map(lambda x: x, self.opt_state))
+        # the step may donate params and opt state: compile it on copies
+        params, opt_state = jax.tree.map(
+            lambda x: jax.device_put(x, may_alias=False),
+            (self.params, self.opt_state))
         roots = np.full(self.tcfg.batch_size, -1, np.int64)
         roots[:min(len(self.graph.train_ids), 8)] = \
             self.graph.train_ids[:8]
@@ -428,17 +430,15 @@ class GNNTrainer:
             b = mb.build_batch(jax.random.key(0), self.g,
                                jnp.asarray(roots, jnp.int32), self.labels,
                                self.fanouts, self.caps, self.sampler)
-        self.params, self.opt_state, *_ = self.train_step(
-            self.params, self.opt_state, b, self._train_feats,
-            self.degrees, 0.0, jax.random.key(0), self.cache, 1.0,
-            self._skips)
+        self.train_step(params, opt_state, b, self._train_feats,
+                        self.degrees, 0.0, jax.random.key(0), self.cache,
+                        1.0, self._skips)
         be = mb.build_batch(jax.random.key(0), self.g,
                             jnp.asarray(roots, jnp.int32), self.labels,
                             self.fanouts, self.eval_caps,
                             self.eval_sampler)
-        self.eval_step(self.params, be, self.feats, self.degrees,
-                       self.cache)
-        self.params, self.opt_state = saved
+        params, cache = self._eval_state()
+        self.eval_step(params, be, self.feats, self.degrees, cache)
         return self
 
     def _set_cache(self, cache) -> None:
@@ -701,15 +701,27 @@ class GNNTrainer:
         with obs_trace.span("eval", cat="eval", n_ids=len(ids)):
             return self._evaluate(ids)
 
+    def _eval_state(self):
+        """(params, cache) for `eval_step`, which runs on the device that
+        holds the whole feature matrix. In mesh mode the replicated state
+        is copied there first: a jit over mesh-placed inputs would leave
+        the partitioning to the compiler, and it cannot partition a
+        Pallas (Mosaic) kernel."""
+        state = (self.params, self.cache)
+        if self.mesh is None:
+            return state
+        return jax.device_put(state, self.feats.sharding)
+
     def _evaluate(self, ids: np.ndarray) -> Dict:
+        params, cache = self._eval_state()
         tot_l, tot_a, tot_n = 0.0, 0.0, 0.0
         for batch in eval_batches(
                 self.graph, ids, self.tcfg.batch_size, self.fanouts,
                 self.eval_caps, sampler=self.eval_sampler,
                 seed=self.seed + 17,
                 device_graph=self.g, labels=self.labels):
-            l, a, n = self.eval_step(self.params, batch, self.feats,
-                                     self.degrees, self.cache)
+            l, a, n = self.eval_step(params, batch, self.feats,
+                                     self.degrees, cache)
             # analysis: allow[no-host-sync-in-hot-path] -- evaluation accumulates on host; eval batches are not prefetch-overlapped
             n = float(n)
             # analysis: allow[no-host-sync-in-hot-path] -- evaluation accumulates on host; eval batches are not prefetch-overlapped

@@ -354,7 +354,7 @@ def test_f64_detector_fires():
     # x64 must be on for a true f64 cast to exist at all (the default
     # config truncates to f32 — itself part of the no-f64 posture); the
     # context keeps the widening strictly inside this test
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(
             lambda x: x.astype("float64"))(jnp.ones(3, jnp.float32))
     assert ja.f64_casts(closed) or ja.f64_avals(closed)

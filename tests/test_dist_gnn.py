@@ -253,7 +253,6 @@ from repro.graphs import synthetic
 from repro.configs.base import GNNConfig, TrainConfig
 from repro.train.gnn_loop import GNNTrainer
 from repro.dist import gnn as dist_gnn
-from repro.dist.sharding import shard_map
 
 g = prepare(synthetic.load("tiny"), oracle=True)
 cfg = GNNConfig(name="t", model="sage", num_layers=2, hidden_dim=16,
@@ -277,6 +276,10 @@ assert np.isfinite(losses).all()
 assert losses[-1] < losses[0], (losses[0], losses[-1])
 ev = tr.evaluate(g.val_ids)
 assert np.isfinite(ev["loss"]) and 0.0 <= ev["acc"] <= 1.0
+# eval runs on one device: on a TPU the compiler cannot partition the
+# Pallas kernels of a jit over mesh-placed inputs
+assert all(len(x.devices()) == 1
+           for x in jax.tree.leaves(tr._eval_state()))
 print("CONVERGE_OK")
 
 # forced halo-mode plan trains too (dropless: r_cap = cap_L)
@@ -287,6 +290,16 @@ l2 = tr2.train_steps(8)
 assert np.isfinite(l2).all()
 print("HALO_MODE_OK")
 
+# with a donating step (the non-CPU default) warmup compiles on copies,
+# so the trainer's own params and opt state survive it
+import functools
+make_steps = dist_gnn.make_sharded_steps
+dist_gnn.make_sharded_steps = functools.partial(make_steps, donate=True)
+tr3 = GNNTrainer(g, cfg, tcfg, "comm_rand", seed=3, mesh=mesh).warmup()
+assert np.isfinite(tr3.train_steps(2)).all()
+dist_gnn.make_sharded_steps = make_steps
+print("DONATE_OK")
+
 # host mirror == device exchange, element for element
 D, Ns, F, K = 4, 8, 5, 12
 rng = np.random.default_rng(0)
@@ -296,8 +309,9 @@ def f(fl, il):
     out, drop = halo.halo_gather(fl[0], il[0], n_per_shard=Ns, r_cap=K,
                                  halo=D // 2, axis="shard")
     return out[None], drop[None]
-m = jax.jit(shard_map(f, mesh, (P("shard"), P("shard")),
-                      (P("shard"), P("shard"))))
+m = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("shard"), P("shard")),
+                          out_specs=(P("shard"), P("shard")),
+                          check_vma=False))
 out_dev, drop_dev = m(jnp.asarray(feats), jnp.asarray(ids))
 out_np, drop_np = halo.halo_gather_np(feats, ids, n_per_shard=Ns,
                                       r_cap=K, halo=D // 2)
@@ -312,8 +326,9 @@ idx = jnp.asarray(rng.integers(0, 16, size=(4, 6, 3)), jnp.int32)
 w = jnp.ones((4, 6, 3), jnp.float32)
 def agg(x, idx, w):
     return gather_agg(x[0], idx[0], w[0], impl="pallas")[None]
-out = jax.jit(shard_map(agg, mesh, (P("shard"), P("shard"), P("shard")),
-                        P("shard")))(x, idx, w)
+out = jax.jit(jax.shard_map(agg, mesh=mesh,
+                            in_specs=(P("shard"), P("shard"), P("shard")),
+                            out_specs=P("shard"), check_vma=False))(x, idx, w)
 ref = np.stack([np.asarray(gather_agg(x[i], idx[i], w[i], impl="jnp"))
                 for i in range(4)])
 np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-6)
@@ -331,5 +346,5 @@ def _run_sub(script):
 def test_four_replica_mesh_subprocess():
     out = _run_sub(FOUR_REPLICA_SCRIPT)
     for marker in ("CONCAT_OK", "CONVERGE_OK", "HALO_MODE_OK",
-                   "MIRROR_OK", "KERNELS_OK"):
+                   "DONATE_OK", "MIRROR_OK", "KERNELS_OK"):
         assert marker in out.stdout, (marker, out.stdout, out.stderr[-3000:])

@@ -134,7 +134,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.batching.stream import BatchStream
 from repro.core.reorder import prepare
 from repro.dist import gnn as dist_gnn
-from repro.dist.sharding import shard_map
 from repro.graphs import synthetic
 
 g = prepare(synthetic.load("tiny"), oracle=True)
@@ -157,9 +156,9 @@ for hplan in (dist_gnn.HaloPlan("halo", 2, ids.shape[1]),
         rows, dropped = dist_gnn.gather_batch_features(
             fl, p, il[0], plan, hplan)
         return rows[None], dropped[None]
-    fn = jax.jit(shard_map(
-        f, mesh, (P("shard", None), P(), P("shard")),
-        (P("shard"), P("shard"))))
+    fn = jax.jit(jax.shard_map(
+        f, mesh=mesh, in_specs=(P("shard", None), P(), P("shard")),
+        out_specs=(P("shard"), P("shard")), check_vma=False))
     out, dropped = fn(feats_local, pos, ids_sh)
     assert int(np.asarray(dropped).sum()) == 0, hplan
     np.testing.assert_array_equal(np.asarray(out), want)
@@ -171,9 +170,10 @@ def f2(fl, p, il):
     rows, dropped = dist_gnn.gather_batch_features(
         fl, p, il[0], plan, hplan)
     return rows[None], dropped[None]
-out, dropped = jax.jit(shard_map(
-    f2, mesh, (P("shard", None), P(), P("shard")),
-    (P("shard"), P("shard"))))(feats_local, pos, ids_sh)
+out, dropped = jax.jit(jax.shard_map(
+    f2, mesh=mesh, in_specs=(P("shard", None), P(), P("shard")),
+    out_specs=(P("shard"), P("shard")), check_vma=False))(
+        feats_local, pos, ids_sh)
 assert int(np.asarray(dropped).sum()) > 0
 out = np.asarray(out)
 for r in range(4):

@@ -73,6 +73,31 @@ def test_gather_agg_repeated_rows_scatter_add():
     assert float(jnp.abs(dx[1:]).max()) == 0.0    # untouched rows stay zero
 
 
+@pytest.mark.parametrize("rows_per_pass", [8, 16])
+def test_gather_agg_dx_multi_pass_matches_ref(monkeypatch, rows_per_pass):
+    """dx split into several source-row passes (large n_src * F on the
+    chip) vs autodiff of the jnp oracle: every source row is hit, so edges
+    fall on both sides of every pass boundary."""
+    from repro.kernels.gather_agg import kernel
+    n, d, r, f = 50, 33, 6, 16
+    # a 16-wide row is lane-padded to 128 float32 in the dx block
+    monkeypatch.setattr(kernel, "_DX_BYTES", rows_per_pass * 128 * 4)
+    assert kernel.dx_block_rows(n, f) == rows_per_pass < n
+    rng = np.random.default_rng(rows_per_pass)
+    idx = jnp.asarray(rng.permutation(np.arange(d * r) % n).reshape(d, r),
+                      jnp.int32)
+    w = jnp.asarray(rng.normal(size=(d, r)), jnp.float32)
+    cot = jnp.asarray(rng.normal(size=(d, f)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(n, f)), jnp.float32)
+
+    def dx(impl):
+        return jax.grad(lambda x: (gather_agg(x, idx, w, impl=impl)
+                                   * cot).sum())(x)
+
+    np.testing.assert_allclose(np.asarray(dx("pallas")),
+                               np.asarray(dx("jnp")), rtol=1e-4, atol=1e-4)
+
+
 def test_resolve_agg_impl():
     assert resolve_agg_impl("jnp") == "jnp"
     assert resolve_agg_impl("pallas") == "pallas"
