@@ -47,8 +47,8 @@ HOT_FUNCTIONS: Dict[str, Tuple[str, ...]] = {
     # the tracer's hot-path entry points must themselves never sync:
     # tracing is sold as zero-device-impact, so the lint bans host-sync
     # idioms inside every function a traced step calls per span
-    "repro/obs/trace.py": ("span", "instant", "note", "flush",
-                           "_emit", "__enter__", "__exit__"),
+    "repro/obs/trace.py": ("span", "instant", "_emit", "__enter__",
+                           "__exit__"),
     "repro/core/minibatch.py": ("_build_batch_impl", "_positions"),
     "repro/sampling/device.py": ("sample", "_sample_level", "_topk_mask",
                                  "_hash_rank01", "epoch_ctx"),

@@ -45,6 +45,7 @@ from repro.batching.order import make_batches
 from repro.batching.policy import BatchPolicy, as_policy
 from repro.core import minibatch as mb
 from repro.graphs.csr import DeviceGraph, Graph
+from repro.obs import trace as obs_trace
 
 
 @dataclass
@@ -119,11 +120,12 @@ class BatchStream:
     def root_batches(self, epoch: int) -> np.ndarray:
         """Root-id batches for `epoch` (cached for the current epoch)."""
         if self._order_cache[0] != epoch:
-            rng = np.random.default_rng((self.seed, epoch))
-            order = self.policy.epoch_order(
-                self.graph.train_ids, self.graph.communities, rng)
-            self._order_cache = (epoch, make_batches(
-                order, self.batch_size, self.drop_last))
+            with obs_trace.span("epoch_order", cat="build", epoch=epoch):
+                rng = np.random.default_rng((self.seed, epoch))
+                order = self.policy.epoch_order(
+                    self.graph.train_ids, self.graph.communities, rng)
+                self._order_cache = (epoch, make_batches(
+                    order, self.batch_size, self.drop_last))
         return self._order_cache[1]
 
     def num_batches(self, epoch: int = None) -> int:
@@ -153,10 +155,12 @@ class BatchStream:
 
     def build(self, roots: np.ndarray, epoch: int, pos: int) -> mb.MiniBatch:
         """Compile/dispatch the static-shape batch for these roots."""
-        return mb._build_batch(
-            self.batch_key(epoch, pos), self.epoch_key(epoch), self.g,
-            jnp.asarray(roots, jnp.int32), self.labels, self.fanouts,
-            self.caps, self.sampler, self.epoch_ctx(epoch))
+        with obs_trace.span("batch_build", cat="build",
+                            epoch=epoch, pos=pos):
+            return mb._build_batch(
+                self.batch_key(epoch, pos), self.epoch_key(epoch), self.g,
+                jnp.asarray(roots, jnp.int32), self.labels, self.fanouts,
+                self.caps, self.sampler, self.epoch_ctx(epoch))
 
     # -- iteration -----------------------------------------------------------
     def _take(self, epoch: int, pos: int) -> mb.MiniBatch:

@@ -93,56 +93,72 @@ def _build_batch_impl(key, epoch_key, g: DeviceGraph, roots, labels_all,
     batch sequence is bit-exact against the synchronous stream."""
     N = g.num_nodes
     B = roots.shape[0]
-    root_mask = roots >= 0
-    level = jnp.where(root_mask, roots, N).astype(jnp.int32)
-    # roots must be sorted for searchsorted-based mapping; keep label order
-    level = jnp.sort(level)
-    labels = jnp.where(root_mask, labels_all[jnp.where(
-        root_mask, roots, 0)], 0)
-
-    # shared per-epoch sampler state (LABOR ranks): computed once per
-    # build instead of once per hop — a pure function of the epoch key,
-    # so hoisting cannot change any pick
-    if shared_ctx is None:
-        shared_ctx = sampler_epoch_ctx(sampler, epoch_key, g)
+    # every device op of the build runs under one of the named scopes
+    # below (op metadata only: they add no op and change no value), so a
+    # device trace splits the build into roots, each hop's sampling,
+    # dedup and position maps, and labels
+    with jax.named_scope("build/roots"):
+        root_mask = roots >= 0
+        level = jnp.where(root_mask, roots, N).astype(jnp.int32)
+        # roots must be sorted for searchsorted-based mapping
+        level = jnp.sort(level)
 
     levels = [level]
     blocks = []
-    keys = jax.random.split(key, len(fanouts))
     for h, (r, cap) in enumerate(zip(fanouts, caps)):
         prev = levels[-1]
-        # shared-randomness samplers (LABOR) draw from the epoch key so the
-        # same source node picks the same neighbors at every hop and batch
-        k_h = epoch_key if sampler.shared_randomness else keys[h]
-        if shared_ctx is not None:
-            srcs, smask = sampler.sample(k_h, g, prev, r, ranks=shared_ctx)
-        else:
-            srcs, smask = sampler.sample(k_h, g, prev, r)
-        all_ids = jnp.concatenate([prev, srcs.reshape(-1)])
-        nxt = jnp.unique(all_ids, size=cap, fill_value=N).astype(jnp.int32)
-        self_pos, self_ok = _positions(nxt, prev)
-        src_pos, src_ok = _positions(nxt, srcs.reshape(-1))
-        blocks.append(Block(
-            src_pos=src_pos.reshape(prev.shape[0], r),
-            self_pos=self_pos,
-            edge_mask=(smask & src_ok.reshape(prev.shape[0], r)
-                       & (srcs < N)),
-            dst_mask=(prev < N) & self_ok,
-        ))
+        with jax.named_scope(f"build/hop{h}/sample"):
+            if h == 0:
+                keys = jax.random.split(key, len(fanouts))
+                # shared per-epoch sampler state (LABOR ranks): computed
+                # once per build instead of once per hop — a pure
+                # function of the epoch key, so hoisting cannot change
+                # any pick
+                if shared_ctx is None:
+                    shared_ctx = sampler_epoch_ctx(sampler, epoch_key, g)
+            # shared-randomness samplers (LABOR) draw from the epoch key
+            # so the same source node picks the same neighbors at every
+            # hop and batch
+            k_h = epoch_key if sampler.shared_randomness else keys[h]
+            if shared_ctx is not None:
+                srcs, smask = sampler.sample(k_h, g, prev, r,
+                                             ranks=shared_ctx)
+            else:
+                srcs, smask = sampler.sample(k_h, g, prev, r)
+        with jax.named_scope(f"build/hop{h}/dedup"):
+            all_ids = jnp.concatenate([prev, srcs.reshape(-1)])
+            nxt = jnp.unique(all_ids, size=cap,
+                             fill_value=N).astype(jnp.int32)
+        with jax.named_scope(f"build/hop{h}/positions"):
+            self_pos, self_ok = _positions(nxt, prev)
+            src_pos, src_ok = _positions(nxt, srcs.reshape(-1))
+            blocks.append(Block(
+                src_pos=src_pos.reshape(prev.shape[0], r),
+                self_pos=self_pos,
+                edge_mask=(smask & src_ok.reshape(prev.shape[0], r)
+                           & (srcs < N)),
+                dst_mask=(prev < N) & self_ok,
+            ))
         levels.append(nxt)
 
     top = levels[-1]
-    # labels aligned to the SORTED root level: re-gather via positions
-    root_pos, _ = _positions(levels[0], jnp.where(root_mask, roots, N))
-    lab_sorted = jnp.zeros((B,), labels_all.dtype).at[root_pos].set(
-        jnp.where(root_mask, labels, 0), mode="drop")
-    lmask = jnp.zeros((B,), bool).at[root_pos].set(root_mask, mode="drop")
+    with jax.named_scope("build/labels"):
+        # labels aligned to the SORTED root level: re-gather via positions
+        labels = jnp.where(root_mask, labels_all[jnp.where(
+            root_mask, roots, 0)], 0)
+        root_pos, _ = _positions(levels[0], jnp.where(root_mask, roots, N))
+        lab_sorted = jnp.zeros((B,), labels_all.dtype).at[root_pos].set(
+            jnp.where(root_mask, labels, 0), mode="drop")
+        lmask = jnp.zeros((B,), bool).at[root_pos].set(root_mask,
+                                                       mode="drop")
+        node_mask = top < N
+        label_mask = lmask & (levels[0] < N)
     return MiniBatch(
         levels=levels,
-        node_mask=top < N,
+        node_mask=node_mask,
         blocks=blocks[::-1],
         labels=lab_sorted,
-        label_mask=lmask & (levels[0] < N),
+        label_mask=label_mask,
     )
 
 

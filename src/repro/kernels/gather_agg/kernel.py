@@ -164,14 +164,15 @@ def _gather_tile_kernel(idx_ref, nidx_ref, *refs, bd: int, r: int,
     combine(rows, t_ref[0] if t_ref else None, o_ref)
 
 
-def gather_tiles(tables, idx, t, combine, out_cols: int, *,
+def gather_tiles(tables, idx, t, combine, out_cols: int, *, name: str,
                  block_dst: int = 0, interpret: bool = False):
     """Run `combine` over destination tiles of gathered rows.
 
     tables: one (n, F) table, or two of one width and dtype (see the
     module docstring for the index rule); idx: (D, r) int32 rows; t: a
     (D, c) float32 per-destination operand blocked with the tile, or None.
-    Returns (D, out_cols) float32."""
+    `name` names the kernel and its scope in a device trace. Returns
+    (D, out_cols) float32."""
     D, r = idx.shape
     views = [row_table(x) for x in tables]
     fp = views[0].shape[-1]
@@ -187,7 +188,7 @@ def gather_tiles(tables, idx, t, combine, out_cols: int, *,
         in_specs.append(pl.BlockSpec((bd, t.shape[1]), lambda i: (i, 0)))
         args.append(jnp.pad(t, ((0, n * bd - D), (0, 0))))
     in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(views)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_gather_tile_kernel, bd=bd, r=r,
                           n_tables=len(views), combine=combine),
         grid=(n,),
@@ -200,7 +201,13 @@ def gather_tiles(tables, idx, t, combine, out_cols: int, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(*args, *views)[:D]
+        name=name,
+    )
+    # the scope holds the kernel alone, so a trace's time under it is the
+    # kernel's
+    with jax.named_scope(name):
+        out = call(*args, *views)
+    return out[:D]
 
 
 # ---------------------------------------------------------------------------
@@ -219,7 +226,8 @@ def gather_agg_fwd_pallas(x, idx, w, *, block_dst: int = 0,
     float32. Returns (n_dst, F) float32. block_dst=0 sizes the tile by
     `dst_tile`; on a TPU an explicit block_dst must be a multiple of 8."""
     return gather_tiles((x,), idx, w, _weighted_sum, x.shape[1],
-                        block_dst=block_dst, interpret=interpret)
+                        name="gather_agg_fwd", block_dst=block_dst,
+                        interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -244,7 +252,8 @@ def gather_agg_bwd_dw_pallas(x, idx, g, *, block_dst: int = 0,
     f = g.shape[1]
     g = jnp.pad(g.astype(jnp.float32), ((0, 0), (0, _round_up(f, 128) - f)))
     return gather_tiles((x,), idx, g, _row_dots, idx.shape[1],
-                        block_dst=block_dst, interpret=interpret)
+                        name="gather_agg_dw", block_dst=block_dst,
+                        interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -294,7 +303,7 @@ def gather_agg_bwd_dx_pallas(idx, w, g, n_src: int, *,
     g = jnp.pad(g.astype(jnp.float32), ((0, n * bd - D), (0, fp - f)))
     smem = functools.partial(pl.BlockSpec, (L,), lambda p, i: (i,),
                              memory_space=pltpu.SMEM)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_bwd_dx_kernel, bd=bd, r=r, one_pass=passes == 1),
         grid=(passes, n),
         in_specs=[smem(), smem(),
@@ -306,4 +315,8 @@ def gather_agg_bwd_dx_pallas(idx, w, g, n_src: int, *,
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=2 * rows * fp * 4 + _TILE_BYTES + (8 << 20)),
         interpret=interpret,
-    )(idx, w, g)[:n_src, :f]
+        name="gather_agg_dx",
+    )
+    with jax.named_scope("gather_agg_dx"):
+        out = call(idx, w, g)
+    return out[:n_src, :f]

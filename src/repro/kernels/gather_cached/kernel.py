@@ -32,4 +32,5 @@ def gather_cached_fwd_pallas(cache, feats, code, *, interpret: bool = False):
         o_ref[...] = rows[0][:, :f]
 
     return gather_tiles((feats, cache), code.reshape(-1, 1), None,
-                        copy_rows, f, interpret=interpret)
+                        copy_rows, f, name="gather_cached_fwd",
+                        interpret=interpret)

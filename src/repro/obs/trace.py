@@ -14,8 +14,9 @@ directly, and computes overlap/stall reports from it (`obs/report.py`).
 Span taxonomy (categories):
 
   step      consumer train-step dispatch (`GNNTrainer._train_one`)
-  build     fused device batch build / epoch-order refresh
-            (`pipeline.builder`)
+  build     device batch build dispatch / epoch-order refresh
+            (`batching.stream`, `pipeline.builder`: `batch_build`,
+            `epoch_order`)
   producer  the async producer thread's build loop
             (`pipeline.prefetch._produce`)
   wait      blocked time: consumer queue get, producer queue put
@@ -23,30 +24,28 @@ Span taxonomy (categories):
             guard skip-counter sync, cache-refill churn sync,
             checkpoint save) — the analyzer gates that NONE of these
             occur mid-epoch
-  device    accumulated device step timing (`DeviceStepTimer`)
   cache     dynamic-cache CLOCK refill dispatch
   ckpt      checkpoint restore / rollback
   loop      epoch envelope (`run_epoch`)
   eval      evaluation pass
 
 Zero-cost when disabled: the module-level tracer defaults to None and
-`span()`/`instant()` return a shared no-op context manager without
-allocating — the hot path pays one global read and one `is None` test.
+`span()` returns a shared no-op context manager (and `instant()` does
+nothing) without allocating — the hot path pays one global read, one
+`is None` test and one check that no JAX profiler session is recording.
 Tracing never syncs the device and never touches RNG or batch data, so
 the loss trajectory is bit-identical with tracing on vs off (pinned by
 tests/test_obs.py).
 
-Device step timing — sync-free by construction: the trainer cannot time
-individual device steps without a per-step `block_until_ready` (exactly
-what the `no-host-sync-in-hot-path` lint forbids). Instead
-`DeviceStepTimer.note` accumulates per-step host dispatch timestamps
-(plus a handle on the step's un-synced output array), and `flush` —
-called only at the EXISTING epoch/checkpoint boundary syncs, after the
-boundary's own `block_until_ready` has drained the device — closes the
-accumulated window into one "device_steps" span with per-step mean
-duration in its args. No new boundary syncs, no mid-epoch syncs; the
-jaxpr audit and lint stay clean because the timer never calls a sync
-primitive itself.
+On the profiler's clock: while a JAX profiler session records
+(`jax.profiler.trace` / `start_trace`), every span is also a TraceMe
+(`jax.profiler.TraceAnnotation`) under its bare name on the calling
+thread's host line, beside the device's ops, with or without a JSONL
+tracer installed. Per-call args (`step=`, `epoch=`) stay in the JSONL
+event only, so one span keeps one name in the profile. Device time is
+the device trace's own: the batch build's phases and the aggregation
+kernels carry `jax.named_scope`s there (`core/minibatch.py`,
+`kernels/gather_agg/kernel.py`).
 """
 from __future__ import annotations
 
@@ -55,6 +54,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 TRACE_SCHEMA_VERSION = 1
 
@@ -67,10 +68,16 @@ def _now_us() -> float:
     return time.perf_counter_ns() / 1e3
 
 
+# True while a JAX profiler session records host events (a TraceMe made
+# then lands in the profile)
+_profiling = TraceAnnotation.is_enabled
+
+
 class _Span:
     """One in-flight "X" (complete) event; also the reusable context
-    manager `Tracer.span` returns."""
-    __slots__ = ("_tracer", "_ev", "_t0")
+    manager `Tracer.span` returns. While the JAX profiler records, the
+    span is also a TraceMe under its bare name."""
+    __slots__ = ("_tracer", "_ev", "_t0", "_tm")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Dict[str, Any]):
@@ -78,6 +85,7 @@ class _Span:
         self._ev = {"name": name, "cat": cat, "ph": "X", "pid": tracer.pid,
                     "tid": threading.get_ident(), "args": args}
         self._t0 = 0.0
+        self._tm = None
 
     def set(self, **args) -> "_Span":
         """Attach args discovered mid-span (e.g. a result count)."""
@@ -85,6 +93,9 @@ class _Span:
         return self
 
     def __enter__(self) -> "_Span":
+        if _profiling():
+            self._tm = TraceAnnotation(self._ev["name"])
+            self._tm.__enter__()
         self._t0 = _now_us()
         return self
 
@@ -92,7 +103,29 @@ class _Span:
         ev = self._ev
         ev["ts"] = self._t0
         ev["dur"] = _now_us() - self._t0
+        if self._tm is not None:
+            self._tm.__exit__(*exc)
+            self._tm = None
         self._tracer._emit(ev)
+
+
+class _ProfilerSpan:
+    """A span while the JAX profiler records and no tracer is installed:
+    a TraceMe under the span's bare name, and nothing else."""
+    __slots__ = ("_tm",)
+
+    def __init__(self, name: str):
+        self._tm = TraceAnnotation(name)
+
+    def set(self, **args) -> "_ProfilerSpan":
+        return self
+
+    def __enter__(self) -> "_ProfilerSpan":
+        self._tm.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._tm.__exit__(*exc)
 
 
 class _NoopSpan:
@@ -269,11 +302,12 @@ class enabled:
 
 
 def span(name: str, cat: str = "host", **args):
-    """A span on the installed tracer, or the shared no-op when tracing
-    is disabled — the ONE line hot paths pay."""
+    """A span on the installed tracer and, while the JAX profiler
+    records, on the profile's host line; the shared no-op when neither
+    records — the ONE line hot paths pay."""
     t = _TRACER
     if t is None:
-        return NOOP
+        return _ProfilerSpan(name) if _profiling() else NOOP
     return t.span(name, cat, **args)
 
 
@@ -281,54 +315,3 @@ def instant(name: str, cat: str = "host", **args) -> None:
     t = _TRACER
     if t is not None:
         t.instant(name, cat, **args)
-
-
-# ---------------------------------------------------------------------------
-# sync-free device step timing
-# ---------------------------------------------------------------------------
-class DeviceStepTimer:
-    """Accumulate per-step dispatch timestamps; close the window ONLY at
-    an existing boundary sync.
-
-    `note(out)` is called once per train step right after dispatch: it
-    records the host timestamp and keeps a reference to the step's
-    un-synced output array (a scalar — holding it is free and keeps the
-    dispatch chain alive for the boundary drain). NO sync happens here.
-
-    `flush(site=...)` is called immediately AFTER the caller's own
-    boundary `block_until_ready` (epoch flush, n-step drain, checkpoint)
-    and emits one "device_steps" span covering first-dispatch -> drained,
-    with `n` steps and the derived per-step mean in its args. The timer
-    itself never calls a sync primitive — the boundary sync it rides is
-    one the trainer already performs, so enabling tracing adds zero
-    host<->device round-trips (the `no-host-sync-in-hot-path` contract).
-    """
-
-    def __init__(self):
-        self._t0: Optional[float] = None
-        self._n = 0
-        self._last = None           # un-synced output of the latest step
-
-    def note(self, out: Any = None) -> None:
-        if _TRACER is None:
-            return
-        if self._t0 is None:
-            self._t0 = _now_us()
-        self._n += 1
-        self._last = out
-
-    def flush(self, site: str = "epoch") -> None:
-        """Emit the accumulated window (call AFTER the boundary drain)."""
-        t = _TRACER
-        if t is None or self._t0 is None:
-            self._t0, self._n, self._last = None, 0, None
-            return
-        end = _now_us()
-        dur = end - self._t0
-        n = self._n
-        t._emit({"name": "device_steps", "cat": "device", "ph": "X",
-                 "ts": self._t0, "dur": dur, "pid": t.pid,
-                 "tid": threading.get_ident(),
-                 "args": {"n": n, "site": site,
-                          "per_step_us": dur / max(n, 1)}})
-        self._t0, self._n, self._last = None, 0, None

@@ -234,10 +234,6 @@ class GNNTrainer:
         # no sync; observed on every `_train_one` dispatch), surfaced as
         # `EpochMetrics.straggler_fraction` + the "straggler/*" hub series
         self.straggler = StragglerMonitor(hub=self.hub)
-        # sync-free device step timing (repro.obs): per-step dispatch
-        # timestamps accumulate and flush into one "device_steps" trace
-        # span ONLY at the existing epoch/n-step boundary drains
-        self._dev_timer = obs_trace.DeviceStepTimer()
         # guarded execution (repro.resilience): None/False disables (the
         # in-jit guard still runs but is never synced or escalated),
         # True = GuardConfig() defaults, or an explicit GuardConfig
@@ -463,10 +459,6 @@ class GNNTrainer:
                     self.params, self.opt_state, batch, self._train_feats,
                     self.degrees, lr, self._dropout_key(), self.cache,
                     poison, self._skips)
-            # sync-free device timing: record the dispatch timestamp +
-            # the un-synced loss; the accumulated window closes at the
-            # NEXT existing boundary drain (epoch flush / n-step sync)
-            self._dev_timer.note(loss)
             if self.cache is not None:
                 # keep the device counters un-synced: a float()/int()
                 # here would serialize away the stream's prefetch overlap
@@ -649,9 +641,6 @@ class GNNTrainer:
                                     n_steps=len(losses)):
                     # analysis: allow[no-host-sync-in-hot-path] -- epoch-boundary flush: one drain per epoch so `time` covers real device work
                     jax.block_until_ready(losses[-1])
-                # the device window closes only AFTER the drain above —
-                # the timer itself never syncs
-                self._dev_timer.flush("epoch")
                 if self._remitter is not None:
                     # per-replica Perfetto tracks, reconstructed from the
                     # queued aux (device already drained, so the host
@@ -691,7 +680,6 @@ class GNNTrainer:
         with obs_trace.span("steps_flush", cat="sync", n=n):
             # analysis: allow[no-host-sync-in-hot-path] -- single batched sync at the END of the n-step run (see comment above: no per-step float)
             out = [float(l) for l in losses]
-        self._dev_timer.flush("train_steps")
         if self._remitter is not None:
             self._remitter.flush(obs_trace.current(),
                                  self.stream.cursor.epoch)

@@ -73,6 +73,26 @@ def test_gather_agg_repeated_rows_scatter_add():
     assert float(jnp.abs(dx[1:]).max()) == 0.0    # untouched rows stay zero
 
 
+def test_gather_agg_kernels_named_in_op_metadata():
+    """The forward and both backward kernels keep their scopes through the
+    custom VJP: a device trace finds each under its own name."""
+    import re
+    x = jnp.ones((40, 16), jnp.float32)
+    idx = jnp.zeros((24, 4), jnp.int32)
+    w = jnp.ones((24, 4), jnp.float32)
+
+    def loss(x, w):
+        return jnp.sum(gather_agg(x, idx, w, impl="pallas") ** 2)
+    txt = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w) \
+        .compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', txt))
+    for kernel in ("gather_agg_fwd", "gather_agg_dx", "gather_agg_dw"):
+        assert any(f"/{kernel}/" in n for n in names), kernel
+    # the backward kernels sit under the transpose of the forward
+    assert any(re.search(r"transpose\(.*\)/gather_agg_dx/", n)
+               for n in names)
+
+
 @pytest.mark.parametrize("rows_per_pass", [8, 16])
 def test_gather_agg_dx_multi_pass_matches_ref(monkeypatch, rows_per_pass):
     """dx split into several source-row passes (large n_src * F on the

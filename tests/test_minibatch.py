@@ -1,10 +1,13 @@
 """Static-shape batch builder: dedup exactness, index validity, policy
 footprint ordering (the paper's Fig 6 mechanism)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import sampling
 from repro.configs.base import BASELINE_POLICY, BEST_POLICY, CommRandPolicy
 from repro.core import minibatch as mb, partition
 from repro.graphs.csr import DeviceGraph
@@ -102,3 +105,44 @@ def test_calibrated_caps_hold(tiny_graph, gdev):
         em = np.asarray(blk.edge_mask)
         dm = np.asarray(blk.dst_mask)
         assert em[dm].any(axis=1).mean() > 0.99
+
+
+_SCOPE = re.compile(r"^jit\(_build_batch\)/build/"
+                    r"(roots|labels|hop(\d+)/(sample|dedup|positions))/")
+_OPCODE = re.compile(r"\s*(?:ROOT )?%?[\w.\-]+ = .*? (fusion|while|sort)\(")
+
+
+@pytest.mark.parametrize("sampler", ["biased", "labor"])
+def test_build_phases_carry_named_scopes(tiny_graph, gdev, sampler):
+    """Every fusion, while and sort of the compiled build that carries an
+    op name sits under one build scope, and each hop shows its sample,
+    dedup and positions scope (the compiler's own rewrites, such as the
+    cumsum split, carry no op name at all and are not counted)."""
+    roots = jnp.asarray(tiny_graph.train_ids[:64], jnp.int32)
+    fanouts, caps = (5, 5), (1024, 1536)
+    txt = mb._build_batch.lower(
+        jax.random.key(0), jax.random.key(1), gdev, roots,
+        jnp.asarray(tiny_graph.labels), fanouts, caps,
+        sampling.resolve(sampler)).compile().as_text()
+    seen, outside, whiles = set(), [], []
+    for line in txt.splitlines():
+        op = _OPCODE.match(line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if op is None or name is None:
+            continue
+        m = _SCOPE.match(name.group(1))
+        if m is None:
+            outside.append(line.strip()[:120])
+            continue
+        seen.add(m.group(1))
+        if op.group(1) == "while":
+            whiles.append(m.group(1))
+    assert outside == []
+    hops = {f"hop{h}/{p}" for h in range(len(fanouts))
+            for p in ("sample", "dedup", "positions")}
+    assert hops <= seen
+    # the searchsorted loops are the position maps' and the labels' (the
+    # others are the samplers' key splits)
+    assert {f"hop{h}/positions" for h in range(len(fanouts))} <= set(whiles)
+    assert all(w.endswith(("/positions", "/sample", "labels"))
+               for w in whiles)

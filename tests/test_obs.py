@@ -115,24 +115,37 @@ def test_tracer_multithread_tids():
     assert tids["main_work"] != tids["thread_work"]
 
 
-def test_device_step_timer_disabled_and_enabled():
-    t = T.DeviceStepTimer()
-    t.note(out=None)                        # disabled: pure no-op
-    t.flush("epoch")
-    with T.enabled(None) as tr:
-        for _ in range(3):
-            t.note(out=None)
-        t.flush(site="epoch")
-        evs = tr.events()
-    assert len(evs) == 1
-    ev = evs[0]
-    assert ev["name"] == "device_steps" and ev["cat"] == "device"
-    assert ev["args"]["n"] == 3 and ev["args"]["site"] == "epoch"
-    assert ev["args"]["per_step_us"] == pytest.approx(ev["dur"] / 3)
-    # window resets after flush
-    with T.enabled(None) as tr2:
-        t.flush("epoch")
-        assert tr2.events() == []
+def _profile_host_names(log_dir) -> list:
+    """Event names on the host Python thread's line of a recorded
+    profile (`python`, or `python3` as the interpreter is named)."""
+    import glob
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    pd = ProfileData.from_file(path)
+    return [e.name for p in pd.planes if p.name == "/host:CPU"
+            for ln in p.lines if ln.name.startswith("python")
+            for e in ln.events]
+
+
+@pytest.mark.parametrize("jsonl", [False, True])
+def test_span_lands_on_profiler_host_line(tmp_path, jsonl):
+    """While a JAX profile records, a span is a TraceMe under its bare
+    name (its args stay out of the name), with or without a JSONL tracer;
+    outside it, an untraced span is the shared no-op again."""
+    assert T.span("batch_build", cat="build") is T.NOOP
+    tracer = T.install(T.Tracer(None)) if jsonl else None
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        for pos in range(3):
+            with T.span("batch_build", cat="build", epoch=0, pos=pos):
+                pass
+    T.uninstall()
+    names = _profile_host_names(tmp_path / "prof")
+    assert names.count("batch_build") == 3
+    assert not [n for n in names if "pos" in n or "epoch=" in n]
+    if jsonl:
+        evs = tracer.events()
+        assert [e["args"]["pos"] for e in evs] == [0, 1, 2]
+    assert T.span("batch_build", cat="build") is T.NOOP
 
 
 # ---------------------------------------------------------------------------
@@ -453,14 +466,18 @@ def test_trainer_emits_expected_span_taxonomy(tiny_graph):
         d = tr.run_epoch(1e-3)
         evs = tracer.events()
     names = {e["name"] for e in evs}
-    assert {"train_step", "epoch", "epoch_flush", "device_steps",
-            "guard_sync", "stats_flush"} <= names
+    assert {"train_step", "epoch", "epoch_flush", "epoch_order",
+            "batch_build", "guard_sync", "stats_flush"} <= names
+    assert "device_steps" not in names
     # straggler fraction surfaced through the epoch dict
     assert 0.0 <= d["straggler"] <= 1.0
-    # the device window covers every step of the epoch
-    (dev,) = [e for e in evs if e["name"] == "device_steps"]
+    # the sync stream's build dispatches and epoch order are build spans
+    builds = [e for e in evs if e["name"] == "batch_build"]
     n_steps = len([e for e in evs if e["name"] == "train_step"])
-    assert dev["args"]["n"] == n_steps and dev["args"]["site"] == "epoch"
+    assert {e["cat"] for e in builds} == {"build"}
+    assert len(builds) >= n_steps - 1
+    (order,) = [e for e in evs if e["name"] == "epoch_order"]
+    assert order["cat"] == "build" and order["args"]["epoch"] == 0
     # trainer-side per-epoch snapshot landed in the hub
     assert tr.hub.epochs and tr.hub.epochs[-1]["epoch"] == 0
 
