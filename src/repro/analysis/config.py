@@ -49,7 +49,8 @@ HOT_FUNCTIONS: Dict[str, Tuple[str, ...]] = {
     # idioms inside every function a traced step calls per span
     "repro/obs/trace.py": ("span", "instant", "_emit", "__enter__",
                            "__exit__"),
-    "repro/core/minibatch.py": ("_build_batch_impl", "_positions"),
+    "repro/core/minibatch.py": ("_build_batch_impl", "_positions", "_dedup",
+                                "_sorted_positions"),
     "repro/sampling/device.py": ("sample", "_sample_level", "_topk_mask",
                                  "_hash_rank01", "epoch_ctx"),
     "repro/featcache/dynamic.py": ("ref_updates", "with_refs",

@@ -212,7 +212,9 @@ def stage_times(g: DeviceGraph, roots, labels_all, fanouts, caps, sampler,
 
       roots_us    root mask + sort (level-0 prep)
       sample_us   all hops' neighbor sampling
-      dedup_us    concat + static-size unique + position remap per hop
+      dedup_us    concat + static-size dedup + position maps from its
+                  sort, per hop (the build's own `mb._dedup` and
+                  `mb._sorted_positions`)
 
     The stages are timed as separate jits over the SAME intermediates the
     fused builder produces, so the split is apples-to-apples with the
@@ -247,13 +249,11 @@ def stage_times(g: DeviceGraph, roots, labels_all, fanouts, caps, sampler,
     @jax.jit
     def dedup_fn(levels, srcs):
         out = []
-        for h, (fan, cap) in enumerate(zip(fanouts, caps)):
+        for h, cap in enumerate(caps):
             prev = levels[h]
             s = srcs[h][0].reshape(-1)
-            nxt = jnp.unique(jnp.concatenate([prev, s]), size=cap,
-                             fill_value=N).astype(jnp.int32)
-            out.append((nxt,) + mb._positions(nxt, prev)
-                       + mb._positions(nxt, s))
+            nxt, new, perm = mb._dedup(jnp.concatenate([prev, s]), cap, N)
+            out.append((nxt,) + mb._sorted_positions(new, perm, cap))
         return out
 
     batch = mb._build_batch(key, epoch_key, g, roots, labels_all,

@@ -1,5 +1,6 @@
 """Static-shape batch builder: dedup exactness, index validity, policy
 footprint ordering (the paper's Fig 6 mechanism)."""
+import hashlib
 import re
 
 import jax
@@ -8,9 +9,11 @@ import numpy as np
 import pytest
 
 from repro import sampling
+from repro.batching import BatchStream, make_policy
 from repro.configs.base import BASELINE_POLICY, BEST_POLICY, CommRandPolicy
 from repro.core import minibatch as mb, partition
 from repro.graphs.csr import DeviceGraph
+from repro.pipeline import DeviceBatchBuilder
 
 
 @pytest.fixture(scope="module")
@@ -141,8 +144,128 @@ def test_build_phases_carry_named_scopes(tiny_graph, gdev, sampler):
     hops = {f"hop{h}/{p}" for h in range(len(fanouts))
             for p in ("sample", "dedup", "positions")}
     assert hops <= seen
-    # the searchsorted loops are the position maps' and the labels' (the
-    # others are the samplers' key splits)
-    assert {f"hop{h}/positions" for h in range(len(fanouts))} <= set(whiles)
-    assert all(w.endswith(("/positions", "/sample", "labels"))
-               for w in whiles)
+    # the position maps come from the dedup's sort, with no search loop:
+    # the loops left are the samplers' key splits and the labels' search
+    assert not any(w.endswith("/positions") for w in whiles)
+    assert all(w.endswith(("/sample", "labels")) for w in whiles)
+
+
+# ---------------------------------------------------------------------------
+# position maps against the binary search they replaced
+# ---------------------------------------------------------------------------
+def _searchsorted_positions(level, ids):
+    """The binary-search position map: clamped `searchsorted` into the
+    sorted level, ok where the level holds the id."""
+    pos = np.minimum(np.searchsorted(level, ids), len(level) - 1)
+    return pos.astype(np.int32), level[pos] == ids
+
+
+def _searchsorted_build(gdev, key, epoch_key, roots, fanouts, caps,
+                        sampler):
+    """Levels, blocks (hop order) and each hop's count of distinct ids, of
+    one batch built with numpy's dedup and binary search over neighbors
+    drawn with the build's keys."""
+    N = gdev.num_nodes
+    roots = np.asarray(roots)
+    level = np.sort(np.where(roots >= 0, roots, N)).astype(np.int32)
+    keys = jax.random.split(key, len(fanouts))
+    ctx = mb.sampler_epoch_ctx(sampler, epoch_key, gdev)
+    kw = {} if ctx is None else {"ranks": ctx}
+    sample = jax.jit(lambda k, prev, r: sampler.sample(k, gdev, prev, r,
+                                                       **kw),
+                     static_argnums=2)
+    levels, blocks, distinct = [level], [], []
+    for h, (r, cap) in enumerate(zip(fanouts, caps)):
+        prev = levels[-1]
+        k_h = epoch_key if sampler.shared_randomness else keys[h]
+        srcs, smask = (np.asarray(x) for x in sample(k_h, prev, r))
+        uniq = np.unique(np.concatenate([prev, srcs.reshape(-1)]))
+        distinct.append(len(uniq))
+        uniq = uniq[:cap]
+        nxt = np.full(cap, N, np.int32)
+        nxt[:len(uniq)] = uniq
+        self_pos, self_ok = _searchsorted_positions(nxt, prev)
+        src_pos, src_ok = _searchsorted_positions(nxt, srcs.reshape(-1))
+        blocks.append({
+            "src_pos": src_pos.reshape(len(prev), r),
+            "self_pos": self_pos,
+            "edge_mask": smask & src_ok.reshape(len(prev), r) & (srcs < N),
+            "dst_mask": (prev < N) & self_ok,
+        })
+        levels.append(nxt)
+    return levels, blocks, distinct
+
+
+def _batch_arrays(b):
+    """A built batch's levels and blocks in the reference's layout."""
+    blocks = [{f: np.asarray(getattr(blk, f)) for f in
+               ("src_pos", "self_pos", "edge_mask", "dst_mask")}
+              for blk in b.blocks[::-1]]
+    return [np.asarray(lv) for lv in b.levels], blocks
+
+
+def _digest(levels, blocks):
+    h = hashlib.sha256()
+    for a in levels + [blk[f] for blk in blocks for f in sorted(blk)]:
+        h.update(str(a.dtype).encode() + str(a.shape).encode())
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("case", ["padded", "dropped"])
+@pytest.mark.parametrize("sampler", ["biased", "uniform", "full", "labor"])
+def test_positions_match_searchsorted(tiny_graph, gdev, sampler, case):
+    """Each hop's positions and masks, taken from the dedup's sort, equal
+    a clamped binary search into the new level bit for bit: with sentinel
+    padding among the roots, and with caps below the distinct ids, so
+    that ids are dropped."""
+    s = sampling.resolve(sampler)
+    roots = np.asarray(tiny_graph.train_ids[:256], np.int32)
+    if case == "padded":
+        roots[::3] = -1
+        fanouts, caps = (5, 5), (1024, 1536)
+    else:
+        roots[-16:] = -1
+        fanouts, caps = (5, 5), (320, 384)
+    key, ekey = jax.random.key(3), jax.random.key(4)
+    b = mb.build_batch(key, gdev, jnp.asarray(roots),
+                       jnp.asarray(tiny_graph.labels), fanouts, caps, s,
+                       epoch_key=ekey)
+    want_levels, want_blocks, distinct = _searchsorted_build(
+        gdev, key, ekey, roots, fanouts, caps, s)
+    got_levels, got_blocks = _batch_arrays(b)
+    for want, got in zip(want_levels, got_levels):
+        assert want.dtype == got.dtype and np.array_equal(want, got)
+    for h, (want, got) in enumerate(zip(want_blocks, got_blocks)):
+        for f in want:
+            assert want[f].dtype == got[f].dtype, (h, f)
+            assert np.array_equal(want[f], got[f]), (h, f)
+    # the cases hold what they name: sentinels among the roots, and
+    # (only where asked) more distinct ids than the cap at every hop
+    assert (got_levels[0] == tiny_graph.num_nodes).any()
+    assert all((d > c) == (case == "dropped")
+               for d, c in zip(distinct, caps))
+
+
+def test_batch_hashes_match_searchsorted(tiny_graph):
+    """12 batches across an epoch end, built through `build_batch` and the
+    fused `DeviceBatchBuilder`, hash equal to the binary-search reference."""
+    fanouts, caps = (5, 5), (512, 1024)
+    st = BatchStream(tiny_graph, make_policy("comm_rand"), 128, fanouts,
+                     caps, seed=11)
+    bld = DeviceBatchBuilder.from_stream(st)
+    last = bld.num_batches - 1
+    cursors = [(0, p) for p in range(last - 5, last + 1)] + \
+        [(1, p) for p in range(6)]
+    labels = jnp.asarray(tiny_graph.labels)
+    for epoch, pos in cursors:
+        roots = st.root_batches(epoch)[pos]
+        key, ekey = st.batch_key(epoch, pos), st.epoch_key(epoch)
+        want = _digest(*_searchsorted_build(
+            st.g, key, ekey, roots, fanouts, caps, st.sampler)[:2])
+        direct = mb.build_batch(key, st.g, jnp.asarray(roots, jnp.int32),
+                                labels, fanouts, caps, st.sampler,
+                                epoch_key=ekey)
+        assert _digest(*_batch_arrays(direct)) == want, (epoch, pos)
+        assert _digest(*_batch_arrays(bld.build(epoch, pos))) == want, \
+            (epoch, pos)
