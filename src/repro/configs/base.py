@@ -162,7 +162,7 @@ def long_context_ok(cfg: ModelConfig) -> bool:
 @dataclass(frozen=True)
 class GNNConfig:
     name: str
-    model: str = "sage"              # sage | gcn | gat
+    model: str = "sage"              # sage | gcn | gat | gat_res
     num_layers: int = 3
     hidden_dim: int = 256
     in_dim: int = 602

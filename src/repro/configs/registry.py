@@ -23,6 +23,7 @@ _GNN_MODULES = {
     "graphsage": "graphsage",
     "gcn": "gcn",
     "gat": "gat",
+    "gat-products": "gat_products",
 }
 
 LM_ARCHS = tuple(_LM_MODULES)

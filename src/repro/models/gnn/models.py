@@ -1,5 +1,6 @@
 """GNN model zoo on static-shape mini-batch towers: GraphSAGE (paper's
-primary), GCN and GAT (paper §6.4).
+primary), GCN and GAT (paper §6.4), and GAT as PyG trains it on
+ogbn-products (`gat_res`).
 
 All layers consume a `Block` (dense (n_dst, fanout) source-position gather +
 self position), so aggregation is a per-edge-weighted reduce over the fanout
@@ -10,6 +11,30 @@ over one shared `gather_agg` call, so the (n_dst, fanout, F) gathered
 intermediate never materializes in HBM on the kernel path — forward or
 backward. `GNNConfig.agg_impl` selects the backend (see
 `repro.kernels.gather_agg.ops.resolve_agg_impl`).
+
+Model ids:
+
+- `sage`, `gcn`: one weight per layer width; ReLU and dropout between
+  layers.
+- `gat`: `gat_heads` heads of `width // gat_heads`, concatenated; where
+  the heads do not fill the width (the last layer), `w_out` projects them
+  to it. ReLU between layers.
+- `gat_res`: GAT (Velickovic et al. 2018) as pytorch_geometric's
+  `examples/ogbn_products_gat.py` builds it (the OGB leaderboard's
+  "GAT (NeighborSampling)"). Hidden layers: `gat_heads` heads of
+  `hidden_dim // gat_heads`, concatenated, plus a bias; the last layer:
+  `gat_heads` heads of `num_classes`, averaged, plus a bias. Every layer
+  adds a linear skip `x_dst @ w_skip + b_skip` of its destinations' input
+  rows. ELU, then dropout, between layers. Scores `LeakyReLU_0.2(a_src .
+  z_j + a_dst . z_i)` over the sampled edges plus one self edge per
+  destination, softmax over them, no attention dropout. Departures from
+  PyG: the program's samplers draw `fanout` neighbors with replacement
+  (PyG's `NeighborSampler` draws without), so a neighbor drawn twice
+  counts twice in the softmax; PyG removes a sampled self edge before it
+  adds the self loop, where the blocks here hold no such edge as the
+  generated graphs have no self edges (a real graph's would be kept as a
+  second self edge); padded destination rows are zeroed after every
+  layer.
 
 `apply_gnn(..., feats_global=True)` additionally composes layer-0 source
 positions with `batch.node_ids`, gathering input features straight from the
@@ -66,6 +91,20 @@ def init_gnn(cfg: GNNConfig, key) -> Params:
                 "w_out": dense_init(ks[3], (H * dh, dout))
                 if H * dh != dout else None,
             })
+        elif cfg.model == "gat_res":
+            H = cfg.gat_heads
+            last = i == cfg.num_layers - 1
+            # hidden: heads of hidden_dim // H, concatenated; last: heads
+            # of num_classes, averaged (a bias one head wide says so)
+            dh = dout if last else dout // H
+            layers.append({
+                "w": dense_init(ks[0], (din, H * dh)),
+                "a_src": dense_init(ks[1], (H, dh)) * 0.1,
+                "a_dst": dense_init(ks[2], (H, dh)) * 0.1,
+                "b": jnp.zeros((dout,)),
+                "w_skip": dense_init(ks[4], (din, dout)),
+                "b_skip": jnp.zeros((dout,)),
+            })
         else:
             raise ValueError(cfg.model)
     return {"layers": layers}
@@ -107,23 +146,42 @@ def gcn_layer(p, x_tab, src_idx, self_idx, edge_mask, deg_src_edge, deg_dst,
     return (agg + h_self) @ p["w"] + p["b"]
 
 
-def gat_layer(p, x_tab, src_idx, self_idx, edge_mask, *, impl="jnp"):
+def _merge_heads(out, b):
+    """(n_dst, H, dh) head outputs -> concatenated (n_dst, H * dh), or
+    averaged (n_dst, dh) where the bias is one head wide (`gat_res`'s last
+    layer)."""
+    n_dst, H, dh = out.shape
+    if H > 1 and b.shape[-1] == dh:
+        return out.mean(axis=1)
+    return out.reshape(n_dst, H * dh)
+
+
+def gat_layer(p, x_tab, src_idx, self_idx, edge_mask, *, impl="jnp",
+              layer: int = 0):
+    """One GAT layer; its phases run under the named scopes
+    `gat/l<layer>/project`, `.../scores` (source and destination scores,
+    LeakyReLU, mask), `.../softmax` and, where `p` has a skip weight,
+    `.../skip`; the aggregation under the kernels' own scopes."""
     H, dh = p["a_src"].shape
     n_dst, r = src_idx.shape
-    z = (x_tab @ p["w"]).reshape(-1, H, dh)           # (n_src, H, dh)
-    # per-SOURCE attention logits: scores are linear in z, so gather the
-    # (n_src, H) scalars instead of (n_dst, r, H, dh) projected rows
-    s_src = jnp.einsum("nhd,hd->nh", z, p["a_src"])
-    z_self = z[self_idx]                              # (n_dst, H, dh)
-    e_src = s_src[src_idx]                            # (n_dst, r, H)
-    e_dst = jnp.einsum("nhd,hd->nh", z_self, p["a_dst"])
-    e_self = jnp.einsum("nhd,hd->nh", z_self, p["a_src"]) + e_dst
-    e = jax.nn.leaky_relu(e_src + e_dst[:, None], 0.2)  # (n_dst, r, H)
-    e = jnp.where(edge_mask[..., None], e, -1e30)
-    e_all = jnp.concatenate(
-        [e, jax.nn.leaky_relu(e_self, 0.2)[:, None]], axis=1)  # + self edge
-    alpha = jax.nn.softmax(e_all, axis=1)             # (n_dst, r+1, H)
-    a_nbr, a_self = alpha[:, :r], alpha[:, r]
+    scope = f"gat/l{layer}"
+    with jax.named_scope(f"{scope}/project"):
+        z = (x_tab @ p["w"]).reshape(-1, H, dh)       # (n_src, H, dh)
+    with jax.named_scope(f"{scope}/scores"):
+        # per-SOURCE attention logits: scores are linear in z, so gather
+        # the (n_src, H) scalars instead of (n_dst, r, H, dh) projected rows
+        s_src = jnp.einsum("nhd,hd->nh", z, p["a_src"])
+        z_self = z[self_idx]                          # (n_dst, H, dh)
+        e_src = s_src[src_idx]                        # (n_dst, r, H)
+        e_dst = jnp.einsum("nhd,hd->nh", z_self, p["a_dst"])
+        e_self = jnp.einsum("nhd,hd->nh", z_self, p["a_src"]) + e_dst
+        e = jax.nn.leaky_relu(e_src + e_dst[:, None], 0.2)  # (n_dst, r, H)
+        e = jnp.where(edge_mask[..., None], e, -1e30)
+    with jax.named_scope(f"{scope}/softmax"):
+        e_all = jnp.concatenate(
+            [e, jax.nn.leaky_relu(e_self, 0.2)[:, None]], axis=1)  # + self
+        alpha = jax.nn.softmax(e_all, axis=1)         # (n_dst, r+1, H)
+        a_nbr, a_self = alpha[:, :r], alpha[:, r]
     if impl == "pallas":
         # fold heads into the row axis: row (s*H + h) of zf is head h of
         # source s, so one gather_agg call reduces all heads, with alpha
@@ -138,9 +196,12 @@ def gat_layer(p, x_tab, src_idx, self_idx, edge_mask, *, impl="jnp"):
     else:
         out = jnp.einsum("nrh,nrhd->nhd", a_nbr, z[src_idx])
     out = out + a_self[..., None] * z_self
-    out = out.reshape(n_dst, H * dh) + p["b"]
+    out = _merge_heads(out, p["b"]) + p["b"]
     if p.get("w_out") is not None:
         out = out @ p["w_out"]
+    if "w_skip" in p:
+        with jax.named_scope(f"{scope}/skip"):
+            out = out + x_tab[self_idx] @ p["w_skip"] + p["b_skip"]
     return out
 
 
@@ -183,7 +244,7 @@ def apply_gnn(cfg: GNNConfig, params: Params, batch: MiniBatch, x,
         feats_global = False
     elif not feats_global:
         x = x * batch.node_mask[:, None].astype(x.dtype)
-    elif cfg.model == "gat":
+    elif cfg.model in ("gat", "gat_res"):
         # GAT projects every unique source row BEFORE gathering (projecting
         # per edge would multiply the matmul FLOPs by the fanout), so the
         # input level is materialized once here; the per-edge (r, H*dh)
@@ -212,10 +273,10 @@ def apply_gnn(cfg: GNNConfig, params: Params, batch: MiniBatch, x,
                           d_src[block.src_pos], deg_dst, impl=impl)
         else:
             x = gat_layer(p, x, src_idx, self_idx, block.edge_mask,
-                          impl=impl)
+                          impl=impl, layer=i)
         x = x * block.dst_mask[:, None].astype(x.dtype)
         if i < len(batch.blocks) - 1:
-            x = jax.nn.relu(x)
+            x = jax.nn.elu(x) if cfg.model == "gat_res" else jax.nn.relu(x)
             if train and cfg.dropout > 0 and dropout_key is not None:
                 keep = 1.0 - cfg.dropout
                 mask = jax.random.bernoulli(

@@ -57,7 +57,7 @@ def test_sage_full_neighborhood_matches_dense(small_setup):
     np.testing.assert_allclose(got, ref, rtol=2e-3, atol=2e-3)
 
 
-@pytest.mark.parametrize("model", ["sage", "gcn", "gat"])
+@pytest.mark.parametrize("model", ["sage", "gcn", "gat", "gat_res"])
 def test_models_forward_finite(small_setup, model, tiny_graph):
     g, gdev, _, _ = small_setup
     cfg = GNNConfig("t", model, 3, 32, g.feat_dim, g.num_classes,
@@ -93,7 +93,7 @@ def test_gnn_gradients_flow(small_setup):
     assert all(np.isfinite(norms)) and sum(norms) > 0
 
 
-@pytest.mark.parametrize("model", ["sage", "gcn", "gat"])
+@pytest.mark.parametrize("model", ["sage", "gcn", "gat", "gat_res"])
 def test_feats_global_matches_pregathered(small_setup, model, tiny_graph):
     """apply_gnn(..., feats_global=True) — layer 0 composing src_pos with
     batch.node_ids — must equal the legacy pre-gathered-x path."""
@@ -113,7 +113,9 @@ def test_feats_global_matches_pregathered(small_setup, model, tiny_graph):
                                rtol=1e-5, atol=1e-5)
 
 
-def test_train_steps_loss_trajectory_matches_across_agg_impl(tiny_graph):
+@pytest.mark.parametrize("model", ["sage", "gat_res"])
+def test_train_steps_loss_trajectory_matches_across_agg_impl(tiny_graph,
+                                                            model):
     """20 optimizer steps through the real trainer: the fused Pallas path
     (interpret mode here) must reproduce the jnp path's loss trajectory."""
     from repro.batching import make_policy
@@ -125,7 +127,7 @@ def test_train_steps_loss_trajectory_matches_across_agg_impl(tiny_graph):
     pol = make_policy("comm_rand", mix=0.125, p=1.0)
     traj = {}
     for impl in ("jnp", "pallas"):
-        cfg = GNNConfig("t", "sage", 2, 32, g.feat_dim, g.num_classes,
+        cfg = GNNConfig("t", model, 2, 32, g.feat_dim, g.num_classes,
                         fanout=(4, 4), agg_impl=impl)
         traj[impl] = GNNTrainer(g, cfg, tcfg, pol, seed=0).train_steps(20)
     np.testing.assert_allclose(traj["pallas"], traj["jnp"],

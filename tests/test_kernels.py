@@ -93,6 +93,30 @@ def test_gather_agg_kernels_named_in_op_metadata():
                for n in names)
 
 
+@pytest.mark.parametrize("f", [47])
+def test_gather_agg_class_width_fwd_bwd_matches_jnp(f):
+    """Forward, dx and dw at a width that is no multiple of 8 (`gat_res`'s
+    last layer: 4 heads of 47 classes folded into rows), Pallas in
+    interpret mode vs the jnp path."""
+    H, n, d, r = 4, 30, 21, 10
+    ks = jax.random.split(jax.random.key(47), 5)
+    x = jax.random.normal(ks[0], (n * H, f), jnp.float32)
+    src = jax.random.randint(ks[1], (d, r), 0, n)
+    idx = (src[:, None, :] * H + jnp.arange(H)[None, :, None]).reshape(
+        d * H, r)
+    w = jax.nn.softmax(jax.random.normal(ks[2], (d * H, r)), axis=1)
+    cot = jax.random.normal(ks[3], (d * H, f), jnp.float32)
+
+    def run(impl):
+        out, vjp = jax.vjp(lambda x, w: gather_agg(x, idx, w, impl=impl),
+                           x, w)
+        return (out,) + vjp(cot)
+
+    for got, want in zip(run("pallas"), run("jnp")):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.parametrize("rows_per_pass", [8, 16])
 def test_gather_agg_dx_multi_pass_matches_ref(monkeypatch, rows_per_pass):
     """dx split into several source-row passes (large n_src * F on the
@@ -264,7 +288,7 @@ def test_moe_gmm_matches_ref(e, c, d, f, dtype, seed):
 # ---------------------------------------------------------------------------
 # model aggregation: agg_impl="pallas" vs agg_impl="jnp" (fwd + bwd)
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("model", ["sage", "gcn", "gat"])
+@pytest.mark.parametrize("model", ["sage", "gcn", "gat", "gat_res"])
 def test_apply_gnn_pallas_matches_jnp(tiny_graph, model):
     import dataclasses
 

@@ -8,7 +8,8 @@ fanout (10, 10, 10): the calibrated caps are (7808, 17536, 20480) for
 COMM-RAND-MIX-12.5% and (8320, 21248, 23040) for the uniform eval policy,
 so the widest layer gathers n_dst = 21248 rows of r = 10 from
 n_src = 23040. Widths cover reddit-like's features (64), the hidden width
-(256) and Reddit's features (602).
+(256), Reddit's features (602), and `gat_res`'s head-folded rows on
+ogbn-products: 128 per hidden head, 47 (the classes) per output head.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and pytest-xdist workers all
@@ -73,7 +74,7 @@ def _kernels(f):
     }
 
 
-@pytest.mark.parametrize("f", [64, 256, 602])
+@pytest.mark.parametrize("f", [47, 64, 128, 256, 602])
 @pytest.mark.parametrize("kernel", ["gather_agg_fwd", "gather_agg_bwd_dx",
                                     "gather_agg_bwd_dw",
                                     "gather_cached_fwd"])
